@@ -1,6 +1,3 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 """Serving driver: batched requests through the slot-based engine with
 decoupled analytics samples per tick (the paper's Listing-1 pattern
 applied to an inference fleet).
@@ -37,6 +34,10 @@ zero lost), a preemption stages them to host and the fleet re-grows
 when the rows return. Fault flags need `--scenario` and route through
 the FleetEngine in continuous mode.
 
+`--model NAME` picks the zoo model (reduced smoke widths by default;
+`--full` builds the published widths). Weights are random, made by
+`init_serving_params`: matrices and embeddings in the model dtype.
+
 Run:  PYTHONPATH=src python examples/serve_lm.py [--disagg]
       PYTHONPATH=src python examples/serve_lm.py --scenario bursty-prefix --paged
       PYTHONPATH=src python examples/serve_lm.py --scenario bursty-multitenant --adapt
@@ -49,8 +50,8 @@ import time
 import jax
 import numpy as np
 
-from repro.configs import get_smoke
-from repro.models import build
+from repro.configs import ARCH_NAMES, get, get_smoke
+from repro.models import build, init_serving_params
 from repro.serve import (
     DisaggConfig,
     EngineConfig,
@@ -60,6 +61,7 @@ from repro.serve import (
 )
 from repro.serve.sched import FleetScheduler
 from repro.serve.traffic import SCENARIOS, replay, scenario
+from repro.utils.compile_cache import use_compile_cache
 
 
 def drive_legacy(eng, cfg, n_requests=10):
@@ -87,6 +89,10 @@ def drive_scenario(eng, cfg, sc, **fault_kw):
 
 def main():
     ap = argparse.ArgumentParser()
+    ap.add_argument("--model", default="qwen2.5-3b", choices=ARCH_NAMES,
+                    help="zoo model to serve")
+    ap.add_argument("--full", action="store_true",
+                    help="published widths instead of the reduced smoke config")
     ap.add_argument("--disagg", action="store_true",
                     help="serve through the prefill/decode-disaggregated engine")
     ap.add_argument("--scenario", default=None, choices=sorted(SCENARIOS),
@@ -127,9 +133,10 @@ def main():
 
         obs_trace.enable()  # before engine build: compile spans land too
 
-    cfg = get_smoke("qwen2.5-3b")
+    use_compile_cache()
+    cfg = get(args.model) if args.full else get_smoke(args.model)
     model = build(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = init_serving_params(model, jax.random.PRNGKey(0))
 
     sc = scenario(args.scenario) if args.scenario else None
     sched = FleetScheduler(sc.tenants, token_budget=2000, aging=0.05) if sc else None
@@ -191,8 +198,10 @@ def main():
     dt = time.time() - t0
 
     tokens_out = eng.stats["tokens_out"]
-    print(f"[{mode}] served {n_requests} requests, {tokens_out} tokens in "
-          f"{len(analytics)} ticks ({tokens_out / dt:.1f} tok/s on CPU)")
+    dev = jax.devices()[0]
+    print(f"[{mode}] {cfg.name}: served {n_requests} requests, {tokens_out} tokens "
+          f"in {len(analytics)} ticks ({tokens_out / dt:.1f} tok/s wall, compiles "
+          f"included, on {dev.platform} {dev.device_kind} x{len(jax.devices())})")
     occ = np.mean([a["active_slots"] for a in analytics])
     print(f"mean slot occupancy {occ:.2f}, final queue depth "
           f"{analytics[-1]['queue_depth']}")

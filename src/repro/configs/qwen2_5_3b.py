@@ -7,7 +7,7 @@ from repro.configs.base import ArchConfig
 CONFIG = ArchConfig(
     name="qwen2.5-3b",
     family="dense",
-    source="hf:Qwen/Qwen2.5-0.5B; hf",
+    source="hf:Qwen/Qwen2.5-3B; hf",
     n_layers=36,
     d_model=2048,
     n_heads=16,

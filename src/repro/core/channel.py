@@ -267,12 +267,15 @@ class StreamChannel:
         codec = self._codec(codec)
         chunk_bytes = chunk_bytes if chunk_bytes is not None else self.chunk_bytes
         if wave_fold is None:
-            # the Pallas fast path pays off compiled (TPU); under the
-            # CPU interpreter the in-scan combine is both cheapest and
-            # memory-strict (the Pallas pass is expensive interpreted)
+            # the Pallas fast path pays off compiled (TPU) in a fully
+            # manual region (a Mosaic call cannot be auto-partitioned);
+            # under the CPU interpreter the in-scan combine is both
+            # cheapest and memory-strict (the Pallas pass is expensive
+            # interpreted)
             from repro.kernels.runtime import on_tpu
 
-            wave_fold = "kernel" if on_tpu() else "scan"
+            manual = not jax.sharding.get_abstract_mesh().auto_axes
+            wave_fold = "kernel" if on_tpu() and manual else "scan"
         if wave_fold not in WAVE_FOLDS:
             raise ValueError(f"wave_fold={wave_fold!r} not in {WAVE_FOLDS}")
         wave_ids = range(self.n_waves) if waves is None else waves

@@ -18,14 +18,14 @@ is live iff ``t < pos[b]`` and, for windowed layers (window > 0),
 ``t >= pos[b] + 1 - window``; the step's own K/V row (k_new/v_new) is
 folded in at the final chunk iff the cursor is still inside the view
 (``pos[b] < mb*bs``) — the same "a full cache drops the new row"
-semantics as the ragged lane write in `decode_step_lm`. GQA folds the
-query heads as (n_kv, group) so the score tile batches over KV heads.
+semantics as the ragged lane write in `decode_step_lm`. GQA groups the
+query heads as (n_kv, group); each KV head is one 2-D score tile.
 
 The dense cache routes through the same kernel with a trivial identity
 table (pool = the (B, S, d) cache itself, one block of size S per slot),
 so both stores share one code path. int8 pools carry per-row symmetric
-scales (nb, bs) that are applied to the K/V chunk right after the DMA —
-dequantization never touches HBM.
+scales (nb, bs), applied to the scores (K) and the probabilities (V)
+of each chunk in VMEM — dequantization never touches HBM.
 
 CPU CI runs this kernel through the Pallas interpreter
 (`resolve_interpret`); numerics are tolerance-matched against
@@ -54,9 +54,9 @@ def _kernel(
     pos_ref,  # (B,) int32 per-slot cursors
     win_ref,  # (1,) int32 layer attention window (<=0 = full)
     # array operands
-    q_ref,    # (1, H, hd) this slot's query
-    kn_ref,   # (1, d_kv) this step's new K row
-    vn_ref,   # (1, d_kv)
+    q_ref,    # (1, n_kv, rep, hd) this slot's query, grouped by KV head
+    kn_ref,   # (1, n_kv, 1, hd) this step's new K row
+    vn_ref,   # (1, n_kv, 1, hd)
     kb_ref,   # (1, bs, d_kv) the table-selected pool block
     vb_ref,   # (1, bs, d_kv)
     *rest,    # [ks_ref, vs_ref,] o_ref, m_scr, l_scr, acc_scr
@@ -81,33 +81,35 @@ def _kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32).reshape(n_kv, rep, hd)
-    k = kb_ref[0]
-    v = vb_ref[0]
-    if quantized:
-        k = k.astype(jnp.float32) * ks_ref[0][:, None]
-        v = v.astype(jnp.float32) * vs_ref[0][:, None]
-    k = k.astype(jnp.float32).reshape(bs, n_kv, hd).swapaxes(0, 1)  # (n_kv, bs, hd)
-    v = v.astype(jnp.float32).reshape(bs, n_kv, hd).swapaxes(0, 1)
-
-    s = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-    ) * scale  # (n_kv, rep, bs)
-
-    t = j * bs + jax.lax.broadcasted_iota(jnp.int32, (n_kv, rep, bs), 2)
+    t = j * bs + jax.lax.broadcasted_iota(jnp.int32, (rep, bs), 1)
     ok = t < pos_b
     ok &= (win <= 0) | (t >= (pos_b + 1) - win)
-    s = jnp.where(ok, s, NEG_INF)
+    k_all = kb_ref[0].astype(jnp.float32)  # (bs, d_kv)
+    v_all = vb_ref[0].astype(jnp.float32)
+    # one 2-D (rep, hd) x (hd, bs) tile per KV head: the (bs, d_kv) block
+    # splits along lanes at head boundaries, never through a transpose
+    for g in range(n_kv):
+        q = q_ref[0, g].astype(jnp.float32)  # (rep, hd)
+        k = k_all[:, g * hd:(g + 1) * hd]    # (bs, hd)
+        v = v_all[:, g * hd:(g + 1) * hd]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # (rep, bs)
+        if quantized:
+            # per-row scales (1, bs) commute with the contraction over hd
+            s = s * ks_ref[0]
+        s = jnp.where(ok, s, NEG_INF)
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[..., None])
-    l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1)
-    acc_scr[...] = acc_scr[...] * alpha[..., None] + jax.lax.dot_general(
-        p, v, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
-    )
-    m_scr[...] = m_new
+        m_prev = m_scr[g]  # (rep, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_scr[g] = l_scr[g] * alpha + p.sum(axis=-1, keepdims=True)
+        pv = p * vs_ref[0] if quantized else p  # V's row scales fold into p
+        acc_scr[g] = acc_scr[g] * alpha + jax.lax.dot_general(
+            pv, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        m_scr[g] = m_new
 
     @pl.when(j == mb - 1)
     def _fin():
@@ -115,21 +117,23 @@ def _kernel(
         # A cursor at/past the view length writes nothing — exactly the
         # lane-masked cache write it replaces. The current token is
         # never length- or window-masked (distance 0 from itself).
-        kn = kn_ref[0].astype(jnp.float32).reshape(n_kv, hd)
-        vn = vn_ref[0].astype(jnp.float32).reshape(n_kv, hd)
-        s_new = (q * kn[:, None, :]).sum(axis=-1) * scale  # (n_kv, rep)
         live = pos_b < total
-        s_new = jnp.where(live, s_new, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s_new)
-        alpha = jnp.exp(m_prev - m_new)
-        # gate, don't rely on underflow: when every pool position is
-        # masked too, m == NEG_INF and exp(s_new - m) would be 1, not 0
-        p_new = jnp.where(live, jnp.exp(s_new - m_new), 0.0)
-        l = l_scr[...] * alpha + p_new
-        acc = acc_scr[...] * alpha[..., None] + p_new[..., None] * vn[:, None, :]
-        denom = jnp.maximum(l, 1e-30)
-        o_ref[0] = (acc / denom[..., None]).reshape(n_kv * rep, hd).astype(o_ref.dtype)
+        for g in range(n_kv):
+            q = q_ref[0, g].astype(jnp.float32)    # (rep, hd)
+            kn = kn_ref[0, g].astype(jnp.float32)  # (1, hd)
+            vn = vn_ref[0, g].astype(jnp.float32)
+            s_new = (q * kn).sum(axis=-1, keepdims=True) * scale  # (rep, 1)
+            s_new = jnp.where(live, s_new, NEG_INF)
+            m_prev = m_scr[g]
+            m_new = jnp.maximum(m_prev, s_new)
+            alpha = jnp.exp(m_prev - m_new)
+            # gate, don't rely on underflow: when every pool position is
+            # masked too, m == NEG_INF and exp(s_new - m) would be 1, not 0
+            p_new = jnp.where(live, jnp.exp(s_new - m_new), 0.0)
+            l = l_scr[g] * alpha + p_new
+            acc = acc_scr[g] * alpha + p_new * vn
+            denom = jnp.maximum(l, 1e-30)
+            o_ref[0, g] = (acc / denom).astype(o_ref.dtype)
 
 
 def paged_decode_attention_kernel(
@@ -165,7 +169,12 @@ def paged_decode_attention_kernel(
     if quantized and (k_scale is None or v_scale is None):
         raise ValueError("int8 KV blocks need k_scale/v_scale")
 
-    q3 = q[:, 0]
+    # free XLA reshapes that give every block full (or unit-padded)
+    # last two dims, the only small blocks the TPU compiler tiles:
+    # head idx = kv_idx * rep + group_idx (layers.attention_decode)
+    q4 = q.reshape(b, n_kv, rep, hd)
+    kn4 = k_new.reshape(b, n_kv, 1, hd)
+    vn4 = v_new.reshape(b, n_kv, 1, hd)
     table = table.astype(jnp.int32)
     pos = pos.astype(jnp.int32)
     win_arr = jnp.asarray(window, jnp.int32).reshape(1)
@@ -176,20 +185,20 @@ def paged_decode_attention_kernel(
     def _blk(b_, j, tbl, pos_, win_):
         return (jnp.maximum(tbl[b_, j], 0), 0, 0)
 
-    def _blk2(b_, j, tbl, pos_, win_):
-        return (jnp.maximum(tbl[b_, j], 0), 0)
+    def _slot(b_, j, tbl, pos_, win_):
+        return (b_, 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, h, hd), lambda b_, j, tbl, pos_, win_: (b_, 0, 0)),
-        pl.BlockSpec((1, d_kv), lambda b_, j, tbl, pos_, win_: (b_, 0)),
-        pl.BlockSpec((1, d_kv), lambda b_, j, tbl, pos_, win_: (b_, 0)),
+        pl.BlockSpec((1, n_kv, rep, hd), _slot),
+        pl.BlockSpec((1, n_kv, 1, hd), _slot),
+        pl.BlockSpec((1, n_kv, 1, hd), _slot),
         pl.BlockSpec((1, bs, d_kv), _blk),
         pl.BlockSpec((1, bs, d_kv), _blk),
     ]
-    operands = [q3, k_new, v_new, k_blocks, v_blocks]
+    operands = [q4, kn4, vn4, k_blocks, v_blocks]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bs), _blk2), pl.BlockSpec((1, bs), _blk2)]
-        operands += [k_scale, v_scale]
+        in_specs += [pl.BlockSpec((1, 1, bs), _blk), pl.BlockSpec((1, 1, bs), _blk)]
+        operands += [k_scale[:, None, :], v_scale[:, None, :]]
 
     kernel = functools.partial(
         _kernel,
@@ -202,14 +211,14 @@ def paged_decode_attention_kernel(
             num_scalar_prefetch=3,
             grid=(b, mb),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, h, hd), lambda b_, j, tbl, pos_, win_: (b_, 0, 0)),
+            out_specs=pl.BlockSpec((1, n_kv, rep, hd), _slot),
             scratch_shapes=[
-                pltpu.VMEM((n_kv, rep), jnp.float32),
-                pltpu.VMEM((n_kv, rep), jnp.float32),
+                pltpu.VMEM((n_kv, rep, 1), jnp.float32),
+                pltpu.VMEM((n_kv, rep, 1), jnp.float32),
                 pltpu.VMEM((n_kv, rep, hd), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, n_kv, rep, hd), q.dtype),
         interpret=interpret,
     )(table, pos, win_arr, *operands)
-    return out[:, None]
+    return out.reshape(b, 1, h, hd)

@@ -27,11 +27,10 @@ _FALSY = {"0", "false", "no", "off"}
 
 @functools.cache
 def on_tpu() -> bool:
-    """True when the default JAX backend is a real TPU."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # backend init can fail in exotic sandboxes
-        return False
+    """True when the default JAX backend is a real TPU. A backend that
+    fails to start raises here rather than quietly selecting the CPU
+    interpreter and the reference paths."""
+    return jax.default_backend() == "tpu"
 
 
 def _env_interpret() -> bool | None:

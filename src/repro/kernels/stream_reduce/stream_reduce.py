@@ -87,18 +87,15 @@ def histogram(
     return out[:n_bins]
 
 
-def _acc_kernel(elems_ref, o_ref, acc_scr, *, n_chunks):
+def _acc_kernel(elems_ref, o_ref):
     ci = pl.program_id(1)
 
     @pl.when(ci == 0)
     def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-    acc_scr[...] = acc_scr[...] + elems_ref[0].astype(jnp.float32)
-
-    @pl.when(ci == n_chunks - 1)
-    def _fin():
-        o_ref[...] = acc_scr[...].astype(o_ref.dtype)
+    # the f32 output block stays resident across the chunk axis
+    o_ref[...] += elems_ref[0].astype(jnp.float32)
 
 
 def chunk_accumulate(
@@ -108,7 +105,11 @@ def chunk_accumulate(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Sum stream elements: out[j] = sum_k elements[k, j] (the reducer
-    group's gradient-chunk fold), tiled over S."""
+    group's gradient-chunk fold), tiled over S.
+
+    Blocks are ``(1, 1, tile)`` over a ``(n_chunks, 1, S)`` view and the
+    output is ``(1, S)``, so the last two block dims are a full dim and a
+    multiple of 128, as the TPU compiler requires."""
     interpret = resolve_interpret(interpret)
     n_chunks, s = elements.shape
     tile = min(tile, s)
@@ -116,14 +117,12 @@ def chunk_accumulate(
     pad = n_t * tile - s
     if pad:
         elements = jnp.pad(elements, ((0, 0), (0, pad)))
-    kernel = functools.partial(_acc_kernel, n_chunks=n_chunks)
     out = pl.pallas_call(
-        kernel,
+        _acc_kernel,
         grid=(n_t, n_chunks),
-        in_specs=[pl.BlockSpec((1, tile), lambda t_, c_: (c_, t_))],
-        out_specs=pl.BlockSpec((tile,), lambda t_, c_: (t_,)),
-        out_shape=jax.ShapeDtypeStruct((n_t * tile,), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((tile,), jnp.float32)],
+        in_specs=[pl.BlockSpec((1, 1, tile), lambda t_, c_: (c_, 0, t_))],
+        out_specs=pl.BlockSpec((1, tile), lambda t_, c_: (0, t_)),
+        out_shape=jax.ShapeDtypeStruct((1, n_t * tile), jnp.float32),
         interpret=interpret,
-    )(elements)
-    return out[:s]
+    )(elements[:, None, :])
+    return out[0, :s]

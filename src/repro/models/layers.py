@@ -23,11 +23,8 @@ Params = dict
 # -- sharding hints -----------------------------------------------------------------
 
 def _ambient_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        return m if m is not None and m.axis_names else None
-    except Exception:
-        return None
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def maybe_shard(x: jax.Array, *entries) -> jax.Array:

@@ -1,7 +1,8 @@
 """Model zoo: one uniform interface over every assigned architecture.
 
     model = build(cfg)
-    params = model.init(key)
+    params = model.init(key)                           # f32 master weights
+    params = init_serving_params(model, key)           # serving: cfg.dtype
     loss, metrics = model.loss(params, batch)          # training
     logits, cache, _ = model.prefill(params, tokens)   # serving
     logits, cache = model.decode_step(params, cache, token)
@@ -50,6 +51,31 @@ def build(cfg) -> Model:
     if cfg.family == "encdec":
         return _build_encdec(cfg)
     return _build_lm(cfg)
+
+
+# the leaves every forward reads through `.astype(cfg.dtype)`: linear
+# weights and biases (`layers.linear`) and embedding tables
+# (`layers.embed`/`unembed`). Norm scales and SSM/MoE extras are read
+# in f32 and keep their dtype.
+_SERVING_CAST_KEYS = frozenset({"w", "b", "table"})
+
+
+def init_serving_params(model: Model, key: jax.Array) -> Params:
+    """Weights for serving: `model.init` with every matrix, bias and
+    embedding table held in ``cfg.dtype``, in one jitted program, so a
+    float32 copy of the whole model never lives on the device. The
+    forward casts those leaves to ``cfg.dtype`` where it reads them, so
+    the outputs are bitwise those of ``jax.jit(model.init)(key)``'s f32
+    parameters. Training keeps `model.init`'s f32 master weights."""
+    dtype = model.cfg.dtype
+
+    def init(k):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, x: x.astype(dtype) if path[-1].key in _SERVING_CAST_KEYS else x,
+            model.init(k),
+        )
+
+    return jax.jit(init)(key)
 
 
 def _build_lm(cfg) -> Model:

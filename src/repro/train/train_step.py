@@ -320,7 +320,10 @@ def make_jitted_step(
     elif ts_cfg.mode == "decoupled":
         graph = train_service_graph(mesh, ts_cfg)
         inner = build_decoupled_step(model, opt_cfg, graph, ts_cfg, data_axes)
-        # manual over the data axes; model stays GSPMD-auto
+        # manual over the data axes; model stays GSPMD-auto unless it
+        # has one device, where a fully manual region lets the wave fold
+        # run the Pallas kernel (Mosaic calls cannot be auto-partitioned)
+        manual_axes = data_axes if model_size > 1 else tuple(mesh.axis_names)
         manual_batch = {
             k: P(*((batch_axes,) + (None,) * (len(batch_like[k].shape) - 1)))
             for k in batch_like
@@ -330,7 +333,7 @@ def make_jitted_step(
             mesh,
             (P(), P(), manual_batch),
             (P(), P(), P()),
-            data_axes,
+            manual_axes,
         )
     else:
         raise ValueError(ts_cfg.mode)

@@ -1,11 +1,5 @@
-"""JAX version compatibility for the shard_map-based SPMD paths.
-
-The repo targets the modern API (``jax.shard_map``, ``jax.make_mesh``
-with ``axis_types``); older jaxlibs (< 0.5) ship shard_map under
-``jax.experimental`` with a ``check_rep`` kwarg and no axis types.
-Routing every SPMD entry point through these two helpers keeps the
-serving/benchmark code importable and runnable on both.
-"""
+"""The two shard_map flavours and the mesh builder every SPMD entry
+point goes through, so the keyword choices live in one place."""
 from __future__ import annotations
 
 import jax
@@ -13,63 +7,27 @@ import jax
 
 def shard_map(f, mesh, in_specs, out_specs):
     """jax.shard_map with value-and-replication checking disabled."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 
 def partial_shard_map(f, mesh, in_specs, out_specs, manual_axes):
     """Partial-auto shard_map: manual over ``manual_axes``, GSPMD-auto
     over the rest (the decoupled train step's model axis)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            axis_names=set(manual_axes),
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset(mesh.axis_names) - set(manual_axes)
-    return _shard_map(
+    return jax.shard_map(
         f,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        auto=auto,
-        check_rep=False,
+        axis_names=set(manual_axes),
+        check_vma=False,
     )
 
 
-def supports_set_mesh() -> bool:
-    """Whether this jax ships ``jax.set_mesh`` (the global-mesh context
-    the partial-auto GSPMD train paths rely on; absent before jax 0.5).
-    Slow-suite tests that drive those paths skip-gate on this instead of
-    failing red on older jaxlibs."""
-    return hasattr(jax, "set_mesh")
-
-
 def make_mesh(axis_shapes, axis_names):
-    """jax.make_mesh with Auto axis types when the API supports them."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            axis_shapes, axis_names, axis_types=(axis_type.Auto,) * len(axis_names)
-        )
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(axis_shapes, axis_names)
-    import math
-
-    import numpy as np
-
-    n = math.prod(axis_shapes)
-    devices = np.asarray(jax.devices()[:n]).reshape(axis_shapes)
-    return jax.sharding.Mesh(devices, axis_names)
+    """jax.make_mesh with every axis Auto."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+    )

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import ARCH_NAMES, cells, get, get_smoke
-from repro.models import build, synthetic_batch
+from repro.models import build, init_serving_params, synthetic_batch
 from repro.train.optimizer import OptConfig, apply_updates, init_opt_state
 
 
@@ -56,6 +56,28 @@ def test_smoke_decode(name):
         tok = jnp.argmax(logits[:, -1:], -1).astype(jnp.int32)
         logits, cache = model.decode_step(params, cache, tok)
         assert jnp.all(jnp.isfinite(logits)), name
+
+
+@pytest.mark.parametrize("name", ["qwen2.5-3b", "hymba-1.5b", "whisper-small"])
+def test_serving_params_cast_only_what_the_forward_casts(name):
+    """Matrices, biases and embedding tables come out in cfg.dtype, the
+    rest keeps the init dtype, and the served logits are bitwise those
+    of the f32 parameters the same jitted init produces."""
+    cfg = get_smoke(name)
+    model = build(cfg)
+    key = jax.random.PRNGKey(0)
+    p32 = jax.jit(model.init)(key)
+    p16 = init_serving_params(model, key)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(p32),
+                            jax.tree.leaves(p16)):
+        cast = path[-1].key in ("w", "b", "table")
+        assert b.dtype == (cfg.dtype if cast else a.dtype), jax.tree_util.keystr(path)
+        assert (a.astype(b.dtype) == b).all(), jax.tree_util.keystr(path)
+    batch = synthetic_batch(cfg, 2, 8)
+    kw = {k: batch[k] for k in ("frames", "patches") if k in batch}
+    want = model.prefill(p32, batch["tokens"], **kw)[0]
+    got = model.prefill(p16, batch["tokens"], **kw)[0]
+    assert (want == got).all(), name
 
 
 def test_full_configs_match_assignment():

@@ -3,15 +3,7 @@ stream machinery, decoupled-vs-conventional equivalence, the three
 paper case-study apps, elastic restart."""
 import pytest
 
-from repro.utils import compat
-
 pytestmark = pytest.mark.slow
-
-needs_set_mesh = pytest.mark.skipif(
-    not compat.supports_set_mesh(),
-    reason="jax.set_mesh unavailable on this jax (< 0.5): the "
-    "partial-auto GSPMD path under a global mesh cannot run",
-)
 
 
 def test_stream_reduce_roundtrip(multidevice):
@@ -38,7 +30,6 @@ print("OK")
 """)
 
 
-@needs_set_mesh
 def test_decoupled_equals_conventional_grads(multidevice):
     multidevice("""
 import jax, jax.numpy as jnp, numpy as np, dataclasses
@@ -185,7 +176,6 @@ print("OK")
 """)
 
 
-@needs_set_mesh
 def test_trainer_crash_resume_and_elastic(multidevice):
     multidevice("""
 import shutil, jax, numpy as np
