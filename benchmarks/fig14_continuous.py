@@ -185,8 +185,8 @@ def _drive(model, params, sc, costs: _Costs, mode: str) -> dict:
         dec = costs.decode(-(-tick["decode_batch"] // N_ROWS)) \
             if tick["decode_batch"] else 0.0
         walls.append(pre + dec)
-        if "kv" in tick and tick["kv"].get("kind") == "paged":
-            st = tick["kv"]
+        if e.kv.kind == "paged":
+            st = e.kv.stats
             # private (non-evictable) blocks never exceed the live-token
             # block demand; cross-slot prefix sharing is what makes the
             # inequality strict (tests/test_kvstore.py asserts equality

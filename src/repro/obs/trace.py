@@ -17,6 +17,14 @@ with tracing on or off. When disabled (the default) every module-level
 emit is a single ``is None`` branch, and ``span()`` returns one cached
 null context manager — hot paths pay one branch and no allocation.
 
+``span()`` also writes to a running ``jax.profiler`` session, when
+there is one, as a ``TraceAnnotation`` of the same name carrying the
+span's attributes as metadata. Those spans land on the profiler's host
+clock, on which the device's ops are traced too, so a gap in the
+device's work can be put down to what the host was doing. A span may
+be opened with or without a tracer installed; with neither a tracer
+nor a session, ``span()`` is the null context manager.
+
 The buffer is a bounded ring (``collections.deque(maxlen=…)``): old
 events fall off, ``dropped`` counts them, and lifecycle accounting
 (`lifecycle_report`) is kept in side counters so invariant checks
@@ -27,6 +35,8 @@ from __future__ import annotations
 import collections
 import time
 from typing import Any, Iterator
+
+from jax.profiler import TraceAnnotation as _ProfilerSpan
 
 Track = tuple[str, str]
 
@@ -46,28 +56,61 @@ class _NullSpan:
 
     __slots__ = ()
 
-    def __enter__(self) -> None:
-        return None
+    def __enter__(self) -> "_NullSpan":
+        return self
 
     def __exit__(self, *exc: object) -> bool:
         return False
 
+    def set(self, **attrs: Any) -> None:
+        """Attributes known only at the span's end (ignored when off)."""
+
 
 _NULL_SPAN = _NullSpan()
 
+# True while a jax.profiler session runs (one native call, ~0.1 us)
+_profiling = _ProfilerSpan.is_enabled
+
 
 class _Span:
-    __slots__ = ("_tracer", "_track")
+    """One span, written to the tracer's ring (when ``tracer`` is given)
+    and to the running profiler session (when there is one)."""
 
-    def __init__(self, tracer: "Tracer", track: Track):
+    __slots__ = ("_tracer", "_name", "_track", "_attrs", "_late", "_annotation")
+
+    def __init__(self, tracer: "Tracer | None", name: str, track: Track,
+                 attrs: dict):
         self._tracer = tracer
+        self._name = name
         self._track = track
+        self._attrs = attrs
+        self._late: dict | None = None
+        self._annotation = None
 
     def __enter__(self) -> "_Span":
+        if self._tracer is not None:
+            self._tracer.begin(self._name, self._track, **self._attrs)
+        if _profiling():
+            self._annotation = _ProfilerSpan(self._name, **self._attrs)
+            self._annotation.__enter__()
         return self
 
+    def set(self, **attrs: Any) -> None:
+        """Attributes known only at the span's end: the ring's E event
+        and the profiler event's metadata carry them."""
+        if self._late is None:
+            self._late = attrs
+        else:
+            self._late.update(attrs)
+
     def __exit__(self, *exc: object) -> bool:
-        self._tracer.end(track=self._track)
+        late = self._late or {}
+        if self._annotation is not None:
+            if late:
+                self._annotation.set_metadata(**late)
+            self._annotation.__exit__(*exc)
+        if self._tracer is not None:
+            self._tracer.end(track=self._track, **late)
         return False
 
 
@@ -117,8 +160,7 @@ class Tracer:
         self._emit(ev)
 
     def span(self, name: str, track: Track = MAIN, **attrs: Any) -> _Span:
-        self.begin(name, track, **attrs)
-        return _Span(self, track)
+        return _Span(self, name, track, attrs)
 
     def complete(self, name: str, dur_s: float, track: Track = MAIN,
                  end_ns: int | None = None, **attrs: Any) -> None:
@@ -243,9 +285,12 @@ def get() -> Tracer | None:
 
 
 def span(name: str, track: Track = MAIN, **attrs: Any):
-    if _TRACER is None:
+    """A ``with`` span on ``track`` in the installed tracer's ring and
+    in the running profiler session; the null span when neither is on.
+    ``.set(**attrs)`` inside the block adds attributes at its end."""
+    if _TRACER is None and not _profiling():
         return _NULL_SPAN
-    return _TRACER.span(name, track, **attrs)
+    return _Span(_TRACER, name, track, attrs)
 
 
 def begin(name: str, track: Track = MAIN, **attrs: Any) -> None:

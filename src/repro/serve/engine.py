@@ -43,7 +43,9 @@ from repro.serve.kvstore import make_kvstore
 from repro.serve.sched import FleetLedger, FleetScheduler
 
 # colocated-engine tracks (obs.trace): process "engine", one thread per
-# phase; requests flow-link through these via request_mark
+# phase; requests flow-link through these via request_mark. A span whose
+# name ends in "_wait" is the host waiting on the chip; every other span
+# is host work (dispatch included)
 _T_PREFILL = ("engine", "prefill")
 _T_DECODE = ("engine", "decode")
 
@@ -277,22 +279,30 @@ class Engine:
         this tick refills this tick. Admitted prompts prefill packed
         (one jitted call), except whole-prompt prefix-cache hits, which
         skip prefill entirely."""
+        with _obs.span("engine.admit", _T_PREFILL) as sp:
+            taken, cold, hits = self._admit_free_slots()
+            sp.set(taken=taken, cold=cold, hits=hits)
+
+    def _admit_free_slots(self) -> tuple[int, int, int]:
+        """`_admit_continuous`'s work: (taken, cold, prefix hits)."""
         free = [i for i, s in enumerate(self.slots) if s is None]
         if not free:
-            return
-        budget, cost_fn = self._page_budget()
-        # dense stores have no page budget; keep the take() call
-        # wire-identical to the pre-paging scheduler interface so
-        # PR-1-style scheduler duck types still work
-        gate = {} if budget is None else {"free_tokens": budget, "cost_fn": cost_fn}
-        taken = self.sched.take(self.tick, max_n=len(free), **gate)
+            return 0, 0, 0
+        with _obs.span("sched.take", _T_PREFILL) as sp:
+            budget, cost_fn = self._page_budget()
+            # dense stores have no page budget; keep the take() call
+            # wire-identical to the pre-paging scheduler interface so
+            # PR-1-style scheduler duck types still work
+            gate = {} if budget is None else {"free_tokens": budget, "cost_fn": cost_fn}
+            taken = self.sched.take(self.tick, max_n=len(free), **gate)
+            sp.set(taken=len(taken))
         cold: list[tuple[int, Request]] = []
         for req in taken:
             slot = free.pop(0)
             self.slots[slot] = req
             entry = self.kv.full_hit(req.prompt)
             if entry is not None:
-                info = self.kv.admit_from_full(slot, entry)
+                info = self.kv.admit_from_full(slot, entry, uid=req.uid)
                 self.tokens = self.tokens.at[slot, 0].set(entry.first)
                 if _obs.enabled():
                     _obs.request_mark(req.uid, "admit:prefix_hit", _T_PREFILL,
@@ -302,22 +312,29 @@ class Engine:
                 self.last_tick["prefix_hit_tokens"] += info["prefix_tokens"]
             else:
                 cold.append((slot, req))
+        hits = len(taken) - len(cold)
         if not cold:
-            return
-        with _obs.span("prefill_packed", _T_PREFILL, batch=len(cold)):
+            return len(taken), 0, hits
+        lens = [int(r.prompt.shape[0]) for _, r in cold]
+        # times the dispatch of the packed prefill, not its device run
+        with _obs.span("engine.prefill", _T_PREFILL, rows=len(cold),
+                       tokens=sum(lens),
+                       bucket=prefill_bucket(max(lens), max_len=self.cfg.max_len)):
             logits, batch = self._prefill.run_batch([r.prompt for _, r in cold])
         if _obs.enabled():
             for slot, req in cold:
                 _obs.request_mark(req.uid, "admit", _T_PREFILL, slot=slot)
         call_nets = []
         for i, (slot, req) in enumerate(cold):
-            n = int(req.prompt.shape[0])
+            n = lens[i]
             cache1 = {k: (jnp.int32(n) if k == "pos" else v[:, i : i + 1])
                       for k, v in batch.items()}
             row_logits = logits[i, -1]
             first = sample_last(logits[i : i + 1])[0]
+            with _obs.span("engine.first_token_wait", _T_PREFILL, uid=req.uid):
+                first_id = int(first)
             info = self.kv.admit(slot, cache1, n, tokens=req.prompt,
-                                 logits=row_logits, first=int(first))
+                                 logits=row_logits, first=first_id, uid=req.uid)
             self.tokens = self.tokens.at[slot, 0].set(first)
             self.stats["prefills"] += 1
             self.stats["prefix_hit_tokens"] += info["prefix_tokens"]
@@ -333,6 +350,7 @@ class Engine:
             self.last_tick["prefill_calls"].append(
                 (prefill_bucket(max(call_nets), max_len=self.cfg.max_len),
                  len(cold)))
+        return len(taken), len(cold), hits
 
     # -- one tick ----------------------------------------------------------
     def step(self) -> None:
@@ -361,11 +379,29 @@ class Engine:
     def _step_continuous(self) -> None:
         self.last_tick = {"prefill_lens": [], "prefill_calls": [],
                           "decode_batch": 0, "prefix_hit_tokens": 0}
-        self._admit_continuous()
-        self.tick += 1
-        active = [i for i, s in enumerate(self.slots) if s is not None]
-        if active:
-            _obs.begin("decode", _T_DECODE, tick=self.tick, batch=len(active))
+        with _obs.span("engine.step", _T_DECODE) as step:
+            self._admit_continuous()
+            self.tick += 1
+            active = [i for i, s in enumerate(self.slots) if s is not None]
+            step.set(rows=len(active))
+            if active:
+                self._decode_continuous(active)
+            # same-tick insertion: slots retired above refill immediately
+            self._admit_continuous()
+            if _obs.enabled():
+                # per-tick KV stats feed only the trace's kv counter and
+                # the registry's kv gauges; readers of the store's state
+                # ask `kv.stats` themselves
+                kv = self.last_tick["kv"] = self.kv.stats
+                _metrics.publish_kv_stats(kv)
+                _obs.counter("kv", {k: kv[k] for k in ("blocks_in_use", "live_tokens")
+                                    if k in kv}, _T_DECODE)
+            self.stats["steps"] += 1
+
+    def _decode_continuous(self, active: list[int]) -> None:
+        """Decode one token for every active slot, then retire the
+        finished ones."""
+        with _obs.span("engine.decode", _T_DECODE, tick=self.tick, rows=len(active)):
             if self._decode_paged is not None:
                 # kernel path: decode attends straight into the pool
                 # through the block tables; the step returns just its
@@ -381,21 +417,15 @@ class Engine:
                 self.kv.absorb(cache, active)
             self.last_logits = logits
             next_tok = sample_last(logits)
+        with _obs.span("engine.token_wait", _T_DECODE):
             next_np = np.asarray(next_tok)
-            _obs.end(_T_DECODE)
-            self.last_tick["decode_batch"] = len(active)
-            for slot in self._retire(next_np):
+        self.last_tick["decode_batch"] = len(active)
+        with _obs.span("engine.retire", _T_DECODE) as sp:
+            freed = self._retire(next_np)
+            for slot in freed:
                 self.kv.free(slot)
-            self.tokens = next_tok[:, None]
-        # same-tick insertion: slots retired above refill immediately
-        self._admit_continuous()
-        self.last_tick["kv"] = self.kv.stats
-        _metrics.publish_kv_stats(self.last_tick["kv"])
-        if _obs.enabled():
-            kv = self.last_tick["kv"]
-            _obs.counter("kv", {k: kv[k] for k in ("blocks_in_use", "live_tokens")
-                                if k in kv}, _T_DECODE)
-        self.stats["steps"] += 1
+            sp.set(finished=len(freed))
+        self.tokens = next_tok[:, None]
 
     def _retire(self, next_np: np.ndarray) -> list[int]:
         """Record this tick's token per active slot; finish requests at

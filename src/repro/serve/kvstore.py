@@ -60,7 +60,11 @@ from repro.core.operators import (
     paged_gather_cache,
     paged_gather_cache_int8,
 )
+from repro.obs import trace as _obs
 from repro.serve.api import KVSpec
+
+# host spans of the paged store (obs.trace): one track for all of them
+_T_KV = ("kv", "store")
 
 
 def make_kvstore(model, slots: int, max_len: int, spec: KVSpec, *, ragged: bool):
@@ -195,7 +199,7 @@ class DenseKVStore:
 
     # -- admission / retirement --------------------------------------------
     def admit(self, slot: int, cache1: dict, length: int, *,
-              tokens=None, logits=None, first=None) -> dict:
+              tokens=None, logits=None, first=None, uid=None) -> dict:
         self.cache = self._mig(self.cache, cache1, slot)
         self.lens[slot] = length
         return {"prefix_tokens": 0}
@@ -305,7 +309,8 @@ class PrefixCache:
 
     # -- registration ------------------------------------------------------
     def register(self, store: "PagedKVStore", tokens, length: int,
-                 row: np.ndarray, cache1=None, logits=None, first=None) -> None:
+                 row: np.ndarray, cache1=None, logits=None, first=None,
+                 uid: int | None = None) -> None:
         bs = store.block_size
         for j in range(1, int(length) // bs + 1):
             key = self._key("chain", tokens, j * bs)
@@ -322,13 +327,14 @@ class PrefixCache:
                 c = nfull * bs
                 blocks = tuple(int(b) for b in row[:nfull])
                 store._prefix_ref(blocks)
+                # host copies: the host waits for the chip here
+                with _obs.span("kv.prefix_copy_wait", _T_KV, uid=uid):
+                    k_tail = np.asarray(cache1["k"][:, 0, c:length])
+                    v_tail = np.asarray(cache1["v"][:, 0, c:length])
+                    logits = np.asarray(logits)
                 self.entries[key] = _FullEntry(
-                    length=int(length),
-                    blocks=blocks,
-                    k_tail=np.asarray(cache1["k"][:, 0, c:length]),
-                    v_tail=np.asarray(cache1["v"][:, 0, c:length]),
-                    logits=np.asarray(logits),
-                    first=int(first),
+                    length=int(length), blocks=blocks, k_tail=k_tail,
+                    v_tail=v_tail, logits=logits, first=int(first),
                 )
             else:
                 self.entries.move_to_end(key)
@@ -607,18 +613,19 @@ class PagedKVStore:
         """The raw pool + block tables for `decode_step_paged`: no
         gather, no dense materialization — the kernel chases the table
         per block. int8 pools ride with their scale sidecars."""
-        pos = np.full(self.slots, self.max_len, np.int32)
-        for i in active or ():
-            pos[i] = self.lens[i]
-        out = {
-            "k_pool": self.k_pool,
-            "v_pool": self.v_pool,
-            "tables": jnp.asarray(self.tables),
-            "pos": jnp.asarray(pos),
-            # new rows (and the int8 dequant target) use the cache
-            # dtype, matching what the view/lane-write path stored
-            "rows_like": jnp.zeros((0,), self._cache_dtype),
-        }
+        with _obs.span("kv.kernel_view", _T_KV):
+            pos = np.full(self.slots, self.max_len, np.int32)
+            for i in active or ():
+                pos[i] = self.lens[i]
+            out = {
+                "k_pool": self.k_pool,
+                "v_pool": self.v_pool,
+                "tables": jnp.asarray(self.tables),
+                "pos": jnp.asarray(pos),
+                # new rows (and the int8 dequant target) use the cache
+                # dtype, matching what the view/lane-write path stored
+                "rows_like": jnp.zeros((0,), self._cache_dtype),
+            }
         if self.quantized:
             out["k_scale"] = self.k_scale
             out["v_scale"] = self.v_scale
@@ -631,92 +638,103 @@ class PagedKVStore:
         minus the view round-trip. int8 pools quantize the rows here
         (per-row symmetric scale) before the scatter; fresh tail blocks
         are zeroed in the same jitted call."""
-        idx, pos, blocks, offs, fresh = self._tail_slots(active)
-        if idx:
-            args = (
-                jnp.asarray(idx, jnp.int32),
-                jnp.asarray(blocks, jnp.int32), jnp.asarray(offs, jnp.int32),
-                jnp.asarray(fresh, jnp.int32),
-            )
-            if self.quantized:
-                (self.k_pool, self.v_pool, self.k_scale,
-                 self.v_scale) = self._scatter(
-                    self.k_pool, self.v_pool, self.k_scale, self.v_scale,
-                    rows_k, rows_v, *args,
+        with _obs.span("kv.absorb_rows", _T_KV) as sp:
+            idx, pos, blocks, offs, fresh = self._tail_slots(active)
+            if idx:
+                sp.set(fresh=len(fresh))
+                args = (
+                    jnp.asarray(idx, jnp.int32),
+                    jnp.asarray(blocks, jnp.int32), jnp.asarray(offs, jnp.int32),
+                    jnp.asarray(fresh, jnp.int32),
                 )
-            else:
-                self.k_pool, self.v_pool = self._scatter(
-                    self.k_pool, self.v_pool, rows_k, rows_v, *args,
-                )
-        for i in active:
-            self.lens[i] = min(self.lens[i] + 1, self.max_len)
+                if self.quantized:
+                    (self.k_pool, self.v_pool, self.k_scale,
+                     self.v_scale) = self._scatter(
+                        self.k_pool, self.v_pool, self.k_scale, self.v_scale,
+                        rows_k, rows_v, *args,
+                    )
+                else:
+                    self.k_pool, self.v_pool = self._scatter(
+                        self.k_pool, self.v_pool, rows_k, rows_v, *args,
+                    )
+            for i in active:
+                self.lens[i] = min(self.lens[i] + 1, self.max_len)
 
     # -- admission / retirement --------------------------------------------
     def admit(self, slot: int, cache1: dict, length: int, *,
-              tokens=None, logits=None, first=None) -> dict:
+              tokens=None, logits=None, first=None,
+              uid: int | None = None) -> dict:
         """Install a prefilled request: shared prefix blocks by
         reference, the rest filled from ``cache1``
         (`migrate_cache_into_blocks`). ``tokens`` enables prefix
         lookup/registration; ``logits``/``first`` additionally register
-        the whole prompt for the skip-prefill fast path."""
-        length = int(length)
-        shared: tuple[int, ...] = ()
-        if self.prefix is not None and tokens is not None:
-            shared = self.prefix.match_chain(tokens, length, self.block_size)
-        start = len(shared) * self.block_size
-        # take the slot's references on shared blocks BEFORE allocating:
-        # _alloc may evict prefix entries, and an unreferenced shared
-        # block would land on the free list mid-admission
-        for b in shared:
-            self.ref[b] += 1
-        n_new = -((start - length) // self.block_size) if length > start else 0
-        new_ids = self._alloc(n_new)
-        if n_new:
-            self._fill_call(cache1, new_ids, start=start)
-        row = np.full(self.max_blocks, -1, np.int32)
-        row[: len(shared)] = shared
-        row[len(shared) : len(shared) + n_new] = new_ids
-        for b in new_ids:
-            self.ref[b] = 1
-        self.tables[slot] = row
-        self.lens[slot] = length
-        if self.prefix is not None and tokens is not None:
-            self.prefix.hit_tokens += start
-            if start:
-                self.prefix.hits += 1
-            else:
-                self.prefix.misses += 1
-            self.prefix.register(self, tokens, length, row,
-                                 cache1=cache1, logits=logits, first=first)
-        return {"prefix_tokens": start}
+        the whole prompt for the skip-prefill fast path. ``uid`` names
+        the request in the trace."""
+        with _obs.span("kv.admit", _T_KV, uid=uid) as sp:
+            length = int(length)
+            shared: tuple[int, ...] = ()
+            if self.prefix is not None and tokens is not None:
+                shared = self.prefix.match_chain(tokens, length, self.block_size)
+            start = len(shared) * self.block_size
+            # take the slot's references on shared blocks BEFORE allocating:
+            # _alloc may evict prefix entries, and an unreferenced shared
+            # block would land on the free list mid-admission
+            for b in shared:
+                self.ref[b] += 1
+            n_new = -((start - length) // self.block_size) if length > start else 0
+            new_ids = self._alloc(n_new)
+            if n_new:
+                self._fill_call(cache1, new_ids, start=start)
+            row = np.full(self.max_blocks, -1, np.int32)
+            row[: len(shared)] = shared
+            row[len(shared) : len(shared) + n_new] = new_ids
+            for b in new_ids:
+                self.ref[b] = 1
+            self.tables[slot] = row
+            self.lens[slot] = length
+            if self.prefix is not None and tokens is not None:
+                self.prefix.hit_tokens += start
+                if start:
+                    self.prefix.hits += 1
+                else:
+                    self.prefix.misses += 1
+                self.prefix.register(self, tokens, length, row,
+                                     cache1=cache1, logits=logits, first=first,
+                                     uid=uid)
+            sp.set(prefix_tokens=start)
+            return {"prefix_tokens": start}
 
     def full_hit(self, tokens) -> _FullEntry | None:
         if self.prefix is None:
             return None
         return self.prefix.match_full(tokens)
 
-    def admit_from_full(self, slot: int, entry: _FullEntry) -> dict:
+    def admit_from_full(self, slot: int, entry: _FullEntry, *,
+                        uid: int | None = None) -> dict:
         """Install a whole cached prompt without running prefill: full
         blocks by reference, the tail rows from the entry's host copy
-        into a fresh private block."""
-        row = np.full(self.max_blocks, -1, np.int32)
-        row[: len(entry.blocks)] = entry.blocks
-        for b in entry.blocks:
-            self.ref[b] += 1
-        rem = entry.length - len(entry.blocks) * self.block_size
-        if rem:
-            (nb,) = self._alloc(1)
-            tail = {"k": jnp.asarray(entry.k_tail)[:, None],
-                    "v": jnp.asarray(entry.v_tail)[:, None],
-                    "pos": jnp.int32(rem)}
-            self._fill_call(tail, [nb], start=0)
-            self.ref[nb] = 1
-            row[len(entry.blocks)] = nb
-        self.tables[slot] = row
-        self.lens[slot] = entry.length
-        self.prefix.hits += 1
-        self.prefix.hit_tokens += entry.length
-        return {"prefix_tokens": entry.length}
+        into a fresh private block. ``uid`` names the request in the
+        trace."""
+        with _obs.span("kv.admit_from_full", _T_KV, uid=uid,
+                       prefix_tokens=entry.length):
+            row = np.full(self.max_blocks, -1, np.int32)
+            row[: len(entry.blocks)] = entry.blocks
+            for b in entry.blocks:
+                self.ref[b] += 1
+            rem = entry.length - len(entry.blocks) * self.block_size
+            if rem:
+                (nb,) = self._alloc(1)
+                tail = {"k": jnp.asarray(entry.k_tail)[:, None],
+                        "v": jnp.asarray(entry.v_tail)[:, None],
+                        "pos": jnp.int32(rem)}
+                self._fill_call(tail, [nb], start=0)
+                self.ref[nb] = 1
+                row[len(entry.blocks)] = nb
+            self.tables[slot] = row
+            self.lens[slot] = entry.length
+            self.prefix.hits += 1
+            self.prefix.hit_tokens += entry.length
+            return {"prefix_tokens": entry.length}
 
     def free(self, slot: int) -> None:
         for b in self.tables[slot]:

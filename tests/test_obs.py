@@ -136,6 +136,43 @@ def test_disabled_tracer_is_one_null_singleton():
     assert trace.get() is None
 
 
+def _profiler_spans(trace_dir, prefixes=("engine.", "kv.", "sched.")) -> list[dict]:
+    """The host spans a profiler session wrote under ``trace_dir``
+    whose names start with ``prefixes``, with their thread and stats."""
+    from jax.profiler import ProfileData
+
+    (path,) = list(trace_dir.glob("**/*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for k, line in enumerate(plane.lines):
+                out += [{"name": e.name, "start": e.start_ns, "end": e.end_ns,
+                         "thread": (plane.name, k), "args": dict(e.stats)}
+                        for e in line.events if e.name.startswith(prefixes)]
+    return sorted(out, key=lambda e: e["start"])
+
+
+def test_span_reaches_the_profiler_only_while_a_session_runs(tmp_path):
+    """With no tracer and no profiler session ``span()`` is the null
+    singleton; inside a session it writes a TraceAnnotation of the same
+    name carrying its attributes, those set at its end included."""
+    import jax
+
+    assert trace.span("x") is trace.span("y")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        s = trace.span("obs.test", ("p", "t"), rows=3)
+        assert s is not trace.span("y")
+        with s as sp:
+            sp.set(finished=1)
+    finally:
+        jax.profiler.stop_trace()
+    assert trace.span("x") is trace.span("y")
+    (ev,) = _profiler_spans(tmp_path, ("obs.",))
+    assert ev["name"] == "obs.test" and ev["args"] == {"rows": 3, "finished": 1}
+    assert trace.get() is None  # the session installs no ring tracer
+
+
 def test_ring_buffer_bounds_events_and_counts_drops():
     t = trace.enable(capacity=8)
     for _ in range(20):
@@ -412,11 +449,14 @@ def test_fault_recovery_keeps_one_lifecycle_span(tiny_model, arm, tmp_path):
     assert export.validate_chrome_trace(export.chrome_trace()) == []
 
 
-def test_tracing_disabled_outputs_bitwise_identical(tiny_model):
-    """Observation never perturbs: the same workload with the tracer off
-    then on yields bit-identical logits every tick and identical output
-    streams (instrumentation is host-side only — no added, reordered,
-    or synchronized device work)."""
+def test_tracing_disabled_outputs_bitwise_identical(tiny_model, tmp_path):
+    """Observation never perturbs: the same workload with the tracer off,
+    then on, then with a profiler session instead yields bit-identical
+    logits every tick and identical output streams (instrumentation is
+    host-side only — no added, reordered, or synchronized device
+    work)."""
+    import jax
+
     from repro.serve import EngineConfig, KVSpec, make_engine
 
     cfg, model, params = tiny_model
@@ -441,10 +481,75 @@ def test_tracing_disabled_outputs_bitwise_identical(tiny_model):
     trace.enable()
     streams_on, logits_on = run()
     trace.disable()
-    assert streams_on == streams_off
-    assert len(logits_on) == len(logits_off)
-    for a, b in zip(logits_off, logits_on):
-        np.testing.assert_array_equal(a, b)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        streams_prof, logits_prof = run()
+    finally:
+        jax.profiler.stop_trace()
+    assert _profiler_spans(tmp_path)  # the session did see the spans
+    for streams, logits in ((streams_on, logits_on), (streams_prof, logits_prof)):
+        assert streams == streams_off
+        assert len(logits) == len(logits_off)
+        for a, b in zip(logits_off, logits):
+            np.testing.assert_array_equal(a, b)
+
+
+def test_profiler_session_records_engine_spans(tiny_model, tmp_path):
+    """A profiler session around a continuous paged engine records the
+    serving spans on the host: two admissions and one decode per tick,
+    ``kv.kernel_view`` inside ``engine.decode`` inside ``engine.step``,
+    ``rows`` equal to the active slots, and the request's ``uid`` on the
+    KV store's admissions (a repeated prompt skips prefill)."""
+    import jax
+
+    from repro.serve import EngineConfig, KVSpec, make_engine
+
+    cfg, model, params = tiny_model
+    eng = make_engine(model, params, EngineConfig(
+        max_batch=4, max_len=64, mode="continuous",
+        kv=KVSpec(kind="paged", block_size=4, prefix_cache=True)))
+    reqs = _requests(cfg)
+    reqs.append(Request(uid=len(reqs), prompt=reqs[0].prompt.copy(), max_new_tokens=4))
+    for r in reqs:
+        assert eng.submit(r)
+    batches = []
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        while not eng.idle():
+            eng.step()
+            batches.append(eng.last_tick["decode_batch"])
+            assert len(batches) < 500
+    finally:
+        jax.profiler.stop_trace()
+    assert "kv" not in eng.last_tick  # per-tick KV stats only when traced
+    spans = _profiler_spans(tmp_path)
+
+    def named(name):
+        return [e for e in spans if e["name"] == name]
+
+    def inside(inner, outer):
+        return (inner["thread"] == outer["thread"]
+                and outer["start"] <= inner["start"] and inner["end"] <= outer["end"])
+
+    steps, decodes = named("engine.step"), named("engine.decode")
+    assert len(steps) == len(batches)
+    assert len(named("engine.admit")) == 2 * len(batches)
+    assert [e["args"]["rows"] for e in decodes] == [b for b in batches if b]
+    assert [e["args"]["rows"] for e in steps] == batches
+    assert len(named("engine.token_wait")) == len(decodes) == len(named("engine.retire"))
+    for view in named("kv.kernel_view"):
+        (dec,) = [d for d in decodes if inside(view, d)]
+        assert any(inside(dec, st) for st in steps)
+    assert sum(e["args"]["finished"] for e in named("engine.retire")) == len(reqs)
+    cold = sorted(e["args"]["uid"] for e in named("kv.admit"))
+    assert cold == list(range(len(reqs) - 1))
+    assert [e["args"]["uid"] for e in named("kv.admit_from_full")] == [len(reqs) - 1]
+    for wait in ("kv.prefix_copy_wait", "engine.first_token_wait"):
+        assert sorted(e["args"]["uid"] for e in named(wait)) == cold
+    assert sum(e["args"]["tokens"] for e in named("engine.prefill")) == sum(
+        int(r.prompt.shape[0]) for r in reqs[:-1])
+    for take in named("sched.take"):
+        assert any(inside(take, a) for a in named("engine.admit"))
 
 
 def test_fleet_trace_schema(tiny_model, tmp_path):
