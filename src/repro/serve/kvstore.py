@@ -613,10 +613,14 @@ class PagedKVStore:
         """The raw pool + block tables for `decode_step_paged`: no
         gather, no dense materialization — the kernel chases the table
         per block. int8 pools ride with their scale sidecars."""
-        with _obs.span("kv.kernel_view", _T_KV):
+        with _obs.span("kv.kernel_view", _T_KV) as sp:
             pos = np.full(self.slots, self.max_len, np.int32)
             for i in active or ():
                 pos[i] = self.lens[i]
+            # the blocks the decode kernel walks for the active slots,
+            # beside the size of the whole tables
+            live = -(-pos[list(active or ())] // self.block_size)
+            sp.set(live_blocks=int(live.sum()), table_blocks=self.tables.size)
             out = {
                 "k_pool": self.k_pool,
                 "v_pool": self.v_pool,

@@ -540,6 +540,9 @@ def test_profiler_session_records_engine_spans(tiny_model, tmp_path):
     for view in named("kv.kernel_view"):
         (dec,) = [d for d in decodes if inside(view, d)]
         assert any(inside(dec, st) for st in steps)
+        # each active slot holds at least its first block; 4 slots x 16
+        assert dec["args"]["rows"] <= view["args"]["live_blocks"] <= 64
+        assert view["args"]["table_blocks"] == 64
     assert sum(e["args"]["finished"] for e in named("engine.retire")) == len(reqs)
     cold = sorted(e["args"]["uid"] for e in named("kv.admit"))
     assert cold == list(range(len(reqs) - 1))
@@ -550,6 +553,29 @@ def test_profiler_session_records_engine_spans(tiny_model, tmp_path):
         int(r.prompt.shape[0]) for r in reqs[:-1])
     for take in named("sched.take"):
         assert any(inside(take, a) for a in named("engine.admit"))
+
+
+def test_kernel_view_span_counts_live_blocks(tiny_model):
+    """``kv.kernel_view`` carries the blocks the paged decode kernel
+    walks, ceil(pos / block_size) over the active slots, beside the
+    size of the whole tables."""
+    import jax.numpy as jnp
+
+    from repro.serve import KVSpec, make_kvstore
+
+    cfg, model, params = tiny_model
+    kv = make_kvstore(model, 3, 32, KVSpec(kind="paged", block_size=8, n_blocks=13),
+                      ragged=True)
+    for slot, n in enumerate((5, 16, 20)):
+        cache = model.init_cache(1, n)
+        cache["pos"] = jnp.int32(n)
+        kv.admit(slot, cache, n)
+    t = trace.enable()
+    kv.kernel_view([0, 2])
+    kv.kernel_view([1])
+    ends = [e for e in t.events if e["ph"] == "E"]
+    assert [e["args"] for e in ends] == [{"live_blocks": 1 + 3, "table_blocks": 12},
+                                         {"live_blocks": 2, "table_blocks": 12}]
 
 
 def test_fleet_trace_schema(tiny_model, tmp_path):

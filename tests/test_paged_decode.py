@@ -9,6 +9,7 @@ cursors, GQA and per-layer windows included. int8 KV trades that for a
 documented logit-divergence budget and double page capacity.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +23,7 @@ from repro.kernels.paged_attention import (
     paged_decode_attention_kernel,
     paged_decode_attention_ref,
 )
+from repro.kernels.paged_attention.paged_attention import chunk_geometry
 from repro.kernels.runtime import ENV_INTERPRET, resolve_interpret
 from repro.kernels.sample import sample_last
 from repro.models import build
@@ -61,23 +63,66 @@ def _admit_random(model, stores, lens, max_len):
 # -- op level: Pallas kernel (interpret) vs reference ------------------------
 
 
-@pytest.mark.parametrize("window", [0, 1, 7])
-@pytest.mark.parametrize("quantized", [False, True])
-def test_kernel_matches_ref(window, quantized):
-    b, mb, bs, n_kv, rep, hd = 3, 4, 8, 2, 4, 16
-    d_kv = n_kv * hd
+def _table(rng, b, mb, bs, pos, idle=(), holes=()):
+    """Block tables over a shuffled pool: each slot maps the blocks that
+    hold its first ``pos`` positions, -1 past them; ``idle`` slots are
+    all -1, ``holes`` are (slot, entry) set to -1 inside the range."""
+    table = rng.permutation(b * mb).astype(np.int32).reshape(b, mb)
+    for i, p in enumerate(pos):
+        table[i, -(-p // bs):] = -1
+    for i in idle:
+        table[i] = -1
+    for i, j in holes:
+        table[i, j] = -1
+    return table
+
+
+# The chunked walk's edges, beside the original ragged batch ("base":
+# slot 0 mid-block, slot 1 full cache, slot 2 one token; unused entries
+# -1, never dereferenced past pos). With d_kv 32 a chunk is 512 tokens.
+KERNEL_GEOMETRIES = {
+    "base": dict(bs=8, mb=4, pos=[11, 32, 1],
+                 table=lambda rng: np.where(
+                     np.arange(4) < [[2], [4], [1]], np.arange(12).reshape(3, 4), -1)),
+    # 8-page chunks over a 12-entry table: a masked 4-page tail chunk;
+    # windows 7 and 300 start mid-chunk (300 also spans two chunks);
+    # slot 0 has a -1 entry inside its range (the zero-block clamp)
+    "tail": dict(bs=64, mb=12, pos=[600, 768, 100],
+                 table=lambda rng: _table(rng, 3, 12, 64, [600, 768, 100], holes=[(0, 3)])),
+    # pos 0, pos on a chunk boundary, an idle slot (pos = mb*bs, all -1)
+    "edges": dict(bs=64, mb=12, pos=[0, 512, 768],
+                  table=lambda rng: _table(rng, 3, 12, 64, [0, 512, 768], idle=[2])),
+    # the dense store's identity table: one block per slot, longer than
+    # a chunk, so chunks are row ranges of it
+    "dense": dict(bs=1024, mb=1, pos=[0, 512, 700, 1024],
+                  table=lambda rng: np.arange(4, dtype=np.int32)[:, None]),
+}
+KERNEL_CASES = [
+    pytest.param(geo, quantized, window,
+                 id=("" if geo == "base" else f"{geo}-") + f"{quantized}-{window}")
+    for geo in KERNEL_GEOMETRIES
+    for window in ([0, 1, 7] if geo == "base" else [0, 1, 7, 300])
+    for quantized in (False, True)
+]
+
+
+@pytest.mark.parametrize("geometry,quantized,window", KERNEL_CASES)
+def test_kernel_matches_ref(geometry, quantized, window):
+    geo = KERNEL_GEOMETRIES[geometry]
+    bs, mb, n_kv, rep, hd = geo["bs"], geo["mb"], 2, 4, 16
+    b, d_kv = len(geo["pos"]), n_kv * hd
+    rows, copies = chunk_geometry(b * mb, bs, mb, d_kv, 1 if quantized else 4)
+    if geometry in ("tail", "edges"):
+        assert copies > 1 and mb % copies and rows * copies == 512
+    if geometry == "dense":
+        assert rows < bs and copies == 1
     q = jnp.asarray(RNG.normal(size=(b, 1, n_kv * rep, hd)), jnp.float32)
     kn = jnp.asarray(RNG.normal(size=(b, d_kv)), jnp.float32)
     vn = jnp.asarray(RNG.normal(size=(b, d_kv)), jnp.float32)
     kb = jnp.asarray(RNG.normal(size=(b * mb, bs, d_kv)), jnp.float32)
     vb = jnp.asarray(RNG.normal(size=(b * mb, bs, d_kv)), jnp.float32)
-    # ragged: slot 0 mid-block, slot 1 full cache, slot 2 one token;
-    # unused table entries are -1 (never dereferenced past pos)
-    table = np.arange(b * mb, dtype=np.int32).reshape(b, mb)
-    table[0, 2:] = -1
-    table[2, 1:] = -1
-    table = jnp.asarray(table)
-    pos = jnp.asarray([11, mb * bs, 1], jnp.int32)
+    table = jnp.asarray(geo["table"](RNG), jnp.int32)
+    pos = jnp.asarray(geo["pos"], jnp.int32)
     scales = {}
     if quantized:
         kb, ks = kv_quantize(kb)
@@ -85,13 +130,19 @@ def test_kernel_matches_ref(window, quantized):
         scales = {"k_scale": ks, "v_scale": vs}
     args = (q, kn, vn, kb, vb, table, pos)
     kw = dict(n_kv=n_kv, window=window, scale=hd**-0.5, **scales)
-    out = paged_decode_attention_kernel(*args, interpret=True, **kw)
+    out = _interpreted_kernel(*args, **{**kw, "window": jnp.int32(window)})
     ref = paged_decode_attention_ref(*args, dequant_dtype=jnp.float32, **kw)
     tol = 2e-2 if quantized else 2e-5
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         atol=tol, rtol=tol,
     )
+
+
+# the window is traced, so the cases of one geometry share a compile
+_interpreted_kernel = jax.jit(
+    functools.partial(paged_decode_attention_kernel, interpret=True),
+    static_argnames=("n_kv", "scale"))
 
 
 def test_interpret_env_override(monkeypatch):

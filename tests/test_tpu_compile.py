@@ -68,13 +68,24 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
-@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8], ids=["fp", "int8"])
-def test_paged_attention_compiles(one_chip, pool_dtype):
+# (slots, pool blocks, block size, table entries): 8 slots of 2,048;
+# the serving cell's pool (32 slots of 4,096 in 16-token blocks); the
+# dense store's identity table (one 4,096-row block a slot)
+PAGED_GEOMETRIES = {"": (B, NB, BS, MB), "cell-": (32, 5121, 16, 256),
+                    "dense-": (32, 32, 4096, 1)}
+
+
+@pytest.mark.parametrize("geometry,pool_dtype", [
+    pytest.param(geo, dt, id=geo + name)
+    for geo in PAGED_GEOMETRIES for dt, name in ((jnp.bfloat16, "fp"), (jnp.int8, "int8"))
+])
+def test_paged_attention_compiles(one_chip, geometry, pool_dtype):
+    b, nb, bs, mb = PAGED_GEOMETRIES[geometry]
     s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    args = [s((B, 1, H, HD), jnp.bfloat16), s((B, N_KV * HD), jnp.bfloat16),
-            s((B, N_KV * HD), jnp.bfloat16), s((NB, BS, N_KV * HD), pool_dtype),
-            s((NB, BS, N_KV * HD), pool_dtype), s((B, MB), jnp.int32), s((B,), jnp.int32)]
-    scales = [s((NB, BS), jnp.float32)] * 2 if pool_dtype == jnp.int8 else []
+    args = [s((b, 1, H, HD), jnp.bfloat16), s((b, N_KV * HD), jnp.bfloat16),
+            s((b, N_KV * HD), jnp.bfloat16), s((nb, bs, N_KV * HD), pool_dtype),
+            s((nb, bs, N_KV * HD), pool_dtype), s((b, mb), jnp.int32), s((b,), jnp.int32)]
+    scales = [s((nb, bs), jnp.float32)] * 2 if pool_dtype == jnp.int8 else []
 
     def fn(*a):
         k_scale, v_scale = a[7:] or (None, None)
