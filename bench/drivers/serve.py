@@ -13,8 +13,8 @@ in it (their 90th and 99th percentiles), output tokens per second, and
 the mean time to first token of the requests sent in it (send to the end
 of the step that produced ``out_tokens[0]``). ``BENCHMARK.json`` picks
 which of them a cell reports. Correctness: a sample of the finished
-requests, the longest among them, scored by the float32 reference
-(``bench/reference``).
+requests, the longest among them, scored by the float32 reference of
+the configuration's architecture (``bench/reference/<model_type>.py``).
 """
 from __future__ import annotations
 
@@ -23,9 +23,8 @@ import time
 
 import numpy as np
 
-from bench import program, traffic, weights
+from bench import arch, program, traffic, weights
 from bench.common import device_record
-from bench.reference import qwen2 as reference
 
 WARM_SEED = 0x5EED  # the shape sweep's prompts: the same in every run
 
@@ -99,7 +98,7 @@ class ClosedLoop:
             new += n - st["seen"]
             if st["seen"] == 0:
                 st["first_at"] = t
-                st["first_token"] = self._first_token(req.prompt)
+                st["first_token"] = self._first_token(req)
                 admitted.append(req)
             else:
                 st["gaps"].append((t, t - st["last"]))
@@ -126,14 +125,19 @@ class ClosedLoop:
             self.seen_prompts.add(r.prompt.tobytes())
         return new
 
-    def _first_token(self, prompt) -> int | None:
-        """The token prefill produced for ``prompt``: fed to the first
-        decode step but not put in ``out_tokens``. The prefix cache keeps
-        it with the whole prompt; None if that entry is already gone."""
+    def _first_token(self, req) -> int | None:
+        """The token prefill produced for ``req``, fed to the first
+        decode step but not put in ``out_tokens``: the request's own
+        ``first_token`` where the program sets one; else the prefix
+        cache's entry for the whole prompt, which keeps it; None if
+        neither has it."""
+        first = getattr(req, "first_token", None)
+        if first is not None:
+            return int(first)
         prefix = getattr(self.eng.kv, "prefix", None)
         if prefix is None:
             return None
-        entry = prefix.entries.get(("full", np.asarray(prompt, np.int64).tobytes()))
+        entry = prefix.entries.get(("full", np.asarray(req.prompt, np.int64).tobytes()))
         return None if entry is None else int(entry.first)
 
 
@@ -220,10 +224,10 @@ def run(ctx) -> tuple[dict, dict, dict]:
 
     cell, cfg, mix = ctx.cell, ctx.config, ctx.mix
     eng_cfg = cell["engine"]
-    arch = program.arch_config(cfg, cfg["compute_dtype"])
-    model = build(arch)
+    model = build(program.arch_config(cfg, cfg["compute_dtype"]))
     tree = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    params = weights.make_params(tree, ctx.seed, program.stored_dtype(cfg["weights_dtype"]))
+    params = weights.make_params(tree, ctx.seed, program.stored_dtype(cfg),
+                                 arch=arch.load(cfg).module)
     kv = KVSpec(kind="paged", block_size=eng_cfg["block_size"], n_blocks=eng_cfg["n_blocks"],
                 prefix_cache=eng_cfg["prefix_cache"],
                 prefix_capacity=eng_cfg.get("prefix_capacity", 256))
@@ -310,9 +314,10 @@ def run(ctx) -> tuple[dict, dict, dict]:
 
 def check(ctx, cfg: dict, cell: dict, finished: list) -> tuple[dict, dict]:
     """Score a seeded sample of finished requests, the longest among
-    them, with the float32 reference: for every served token (and the
-    token prefill fed to the first decode step), the gap by which its
-    reference logit lies below the reference's best."""
+    them, with the float32 reference of the configuration's
+    architecture: for every served token (and the token prefill fed to
+    the first decode step), the gap by which its reference logit lies
+    below the reference's best."""
     jax = ctx.jax
     jnp = jax.numpy
     chk = cell["check"]
@@ -324,7 +329,8 @@ def check(ctx, cfg: dict, cell: dict, finished: list) -> tuple[dict, dict]:
     rng = traffic.rng_for(ctx.seed, 11)
     pick = [longest] + list(rng.permutation(rest)[: chk["requests"] - 1])
     seq_len, n_pos = chk["pad_tokens"], chk["pad_positions"]
-    W = reference.make_weights(cfg, ctx.seed, program.stored_dtype(cfg["weights_dtype"]))
+    reference = arch.load(cfg).reference
+    W = reference.make_weights(cfg, ctx.seed, program.stored_dtype(cfg))
     gaps, cgaps, scored = [], [], 0
     for i in pick:
         st = finished[i]

@@ -7,11 +7,12 @@ its first three steps on the ring's first three batches; those steps
 compile it and are what the reference follows. The window then calls
 the same step on the ring, each step ended by ``block_until_ready``.
 
-Correctness, against ``bench/reference`` redoing the three steps in
-float32 at full precision on one chip (rows in blocks, gradients
-summed): each step's loss; per leaf, the norm of the first gradient as
-the optimizer got it (its first moment after step 1 over ``1 - beta1``);
-per leaf, the norm of the parameters' change after the three steps.
+Correctness, against the reference of the configuration's architecture
+(``bench/reference/<model_type>.py``) redoing the three steps in float32
+at full precision on one chip (rows in blocks, gradients summed): each
+step's loss; per leaf, the norm of the first gradient as the optimizer
+got it (its first moment after step 1 over ``1 - beta1``); per leaf, the
+norm of the parameters' change after the three steps.
 A gap of norms is taken against the larger of the reference's norm of
 that leaf and of the median leaf; leaves whose reference gradient is
 under a thousandth of the median leaf's move by round-off alone and are
@@ -25,9 +26,8 @@ import time
 
 import numpy as np
 
-from bench import program, traffic, weights
+from bench import arch, program, traffic, weights
 from bench.common import device_record
-from bench.reference import qwen2 as reference
 
 N_CHECKED = 3  # steps the reference follows
 
@@ -63,8 +63,8 @@ def run(ctx) -> tuple[dict, dict, dict]:
     from repro.utils.compat import make_mesh
 
     cell, cfg, mix = ctx.cell, ctx.config, ctx.mix
-    arch = program.arch_config(cfg, cfg["compute_dtype"])
-    model = build(arch)
+    model = build(program.arch_config(cfg, cfg["compute_dtype"]))
+    plugin = arch.load(cfg)
     mesh = make_mesh(tuple(cell["mesh"]), ("data", "model"))
     rows = mix["rows_per_chip"] * mesh.shape["data"]
     tree = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -76,22 +76,21 @@ def run(ctx) -> tuple[dict, dict, dict]:
         step, in_sh = make_jitted_step(model, mesh, opt_cfg, ts, tree, batch_like, donate=True)
         from repro.train.optimizer import init_opt_state
 
-        params = weights.make_params(tree, ctx.seed, program.stored_dtype(cfg["weights_dtype"]),
-                                     out_shardings=in_sh[0])
+        params = weights.make_params(tree, ctx.seed, program.stored_dtype(cfg),
+                                     out_shardings=in_sh[0], arch=plugin.module)
         opt_state = jax.jit(lambda p: init_opt_state(opt_cfg, p), out_shardings=in_sh[1])(params)
         ring = [jax.device_put(traffic.train_batch(mix, cfg["vocab_size"], ctx.seed, i, rows),
                                in_sh[2]) for i in range(mix["ring"])]
         leaf_norms = jax.jit(lambda t: jax.tree.map(
             lambda x: jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)))), t))
-        stored = program.stored_dtype(cfg["weights_dtype"])
+        stored = program.stored_dtype(cfg)
 
         @jax.jit
         def change_norms(p, key):
             flat, treedef = jax.tree_util.tree_flatten_with_path(p)
-            out = [jnp.sqrt(jnp.sum(jnp.square(x - weights.leaf(key, weights.path_name(q),
-                                                               x.shape, stored(
-                                                                   weights.path_name(q))))))
-                   for q, x in flat]
+            out = [jnp.sqrt(jnp.sum(jnp.square(x - weights.leaf(
+                key, weights.path_name(q), x.shape, stored(weights.path_name(q)),
+                arch=plugin.module)))) for q, x in flat]
             return jax.tree_util.tree_unflatten(treedef, out)
 
         TA = jax.profiler.TraceAnnotation
@@ -168,7 +167,9 @@ def reference_steps(ctx, rows: int, precision: str, fault: str | None = None) ->
     jnp = jax.numpy
     cell, cfg, mix = ctx.cell, ctx.config, ctx.mix
     o = cell["optimizer"]
-    stored = program.stored_dtype(cfg["weights_dtype"])
+    plugin = arch.load(cfg)
+    reference = plugin.reference
+    stored = program.stored_dtype(cfg)
     shapes = reference.leaf_shapes(cfg)
     key = weights.base_key(ctx.seed)
     W = reference.make_weights(cfg, ctx.seed, stored)
@@ -208,7 +209,8 @@ def reference_steps(ctx, rows: int, precision: str, fault: str | None = None) ->
     @jax.jit
     def change_norms(W, key):
         return {k: jnp.sqrt(jnp.sum(jnp.square(
-            W[k].astype(jnp.float32) - weights.leaf(key, k, s, stored(k)).astype(jnp.float32))))
+            W[k].astype(jnp.float32)
+            - weights.leaf(key, k, s, stored(k), arch=plugin.module).astype(jnp.float32))))
             for k, s in shapes.items()}
 
     m, v = zeros(), zeros()

@@ -29,7 +29,7 @@ F8_MAX = 448.0  # largest finite float8_e4m3fn
 def dims(cfg: dict) -> dict:
     d = cfg["hidden_size"]
     h = cfg["num_attention_heads"]
-    hd = d // h
+    hd = cfg.get("head_dim") or d // h
     return {"d": d, "h": h, "kv": cfg["num_key_value_heads"], "hd": hd,
             "ff": cfg["intermediate_size"], "V": cfg["vocab_size"],
             "L": cfg["num_hidden_layers"], "eps": cfg["rms_norm_eps"],

@@ -15,13 +15,13 @@ import os
 import sys
 from pathlib import Path
 
-TINY = {"hidden_size": 128, "intermediate_size": 256, "num_attention_heads": 4,
-        "vocab_size": 512, "num_hidden_layers": 2}
-
 
 def shrink(cell: dict, config: dict, mix: dict) -> tuple[dict, dict, dict]:
-    config = dict(config, **TINY)
-    config["num_key_value_heads"] = min(config["num_key_value_heads"], 2)
+    """The configuration cut by its architecture's ``tiny()``; the
+    cell and its traffic cut here, by the cell's driver."""
+    from bench import arch
+
+    config = arch.load(config).module.tiny(config)
     if cell["driver"] == "serve":
         cell = dict(cell, warm_batch=2, warm_ticks=20,
                     engine=dict(cell["engine"], max_batch=4, max_len=256, n_blocks=65),
@@ -31,7 +31,6 @@ def shrink(cell: dict, config: dict, mix: dict) -> tuple[dict, dict, dict]:
                    output=dict(mix["output"], median=16, min=8, max=64),
                    shared_prefix=dict(mix["shared_prefix"], tokens=16))
     else:
-        config["num_key_value_heads"] = config["num_attention_heads"]
         cell = dict(cell, step=dict(cell["step"], wire_chunk_bytes=1 << 16))
         mix = dict(mix, seq=128, rows_per_chip=2, ring=4)
     return cell, config, mix

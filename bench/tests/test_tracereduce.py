@@ -95,8 +95,8 @@ def test_idle_gaps_go_to_the_host_spans(trace, dev):
 def test_paged_attention_roofline_arithmetic(trace, dev):
     """The fixture's kernel call: 8 slots of a GQA layer (16 heads of
     128, 2 KV heads) attending 1,024 positions each."""
-    cfg = {"hidden_size": 2048, "num_attention_heads": 16, "num_key_value_heads": 2,
-           "num_hidden_layers": 1}
+    cfg = {"model_type": "qwen2", "hidden_size": 2048, "num_attention_heads": 16,
+           "num_key_value_heads": 2, "num_hidden_layers": 1}
     flops, nbytes = pa.cost(cfg, [1024] * 8)
     assert flops == 4 * 2048 * 1024 * 8
     assert nbytes == 2 * 256 * 2 * 1024 * 8 + 2 * 2 * 2048 * 8
